@@ -104,7 +104,8 @@ class SyncEngine:
         return self.optimizer.init(params)
 
     def update(self, grads: Any, opt_state: Any, params: Any):
-        return self.optimizer.update(grads, opt_state, params)
+        with jax.named_scope("sync.update"):
+            return self.optimizer.update(grads, opt_state, params)
 
     def check_opt_layout(self, opt_state: Any, num_clients: int = 1) -> None:
         if isinstance(opt_state, jax.Array) or _is_flat_adamw_state(opt_state):
@@ -117,10 +118,12 @@ class SyncEngine:
     # -- elastic leg --------------------------------------------------------
     def exchange_multiclient(self, client_params: Any, center: Any, alpha):
         """One elastic exchange over C stacked replicas (eqs. 2+3)."""
-        if self.flat_exchange:
-            return elastic_exchange_multiclient_flat(client_params, center,
-                                                     alpha)
-        return elastic_exchange_multiclient(client_params, center, alpha)
+        with jax.named_scope("sync.elastic"):
+            if self.flat_exchange:
+                return elastic_exchange_multiclient_flat(client_params,
+                                                         center, alpha)
+            return elastic_exchange_multiclient(client_params, center,
+                                                alpha)
 
 
 def _is_flat_adamw_state(opt_state: Any) -> bool:

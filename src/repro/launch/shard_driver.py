@@ -264,8 +264,9 @@ def make_device_step(model: Model, optimizer: Optimizer, sync: SyncConfig,
 
     def device_exchange(state):
         alpha = sync.esgd_alpha / ex_comm.resolve_size()
-        new_p, new_c = elastic_exchange_sharded(
-            spec, state["params"], state["center"], alpha, comm=ex_comm)
+        with jax.named_scope("sync.elastic"):
+            new_p, new_c = elastic_exchange_sharded(
+                spec, state["params"], state["center"], alpha, comm=ex_comm)
         return dict(state, params=new_p, center=new_c)
 
     return device_step, device_exchange
@@ -581,67 +582,77 @@ def drive(model: Model, optimizer: Optimizer, sync: SyncConfig, batches,
             if inj is not None else None)
     attempts: dict[int, int] = {}    # unit -> spawn generation
     history = []
-    for i, batch in enumerate(batches):
-        if inj is not None:
-            joiners = [u for u in inj.restart_units(i)
-                       if not live.is_live(u)]
-            if joiners:
-                delay = max(inj.restart_delay(u, attempts.get(u, 0)) or 0.0
-                            for u in joiners)
-                for u in joiners:
-                    attempts[u] = attempts.get(u, 0) + 1
+
+    def membership_events(i):
+        """Admit the joiners and evict the dead of step ``i``: each
+        re-lays the state out and re-jits the step."""
+        nonlocal state, p, step, sync
+        joiners = [u for u in inj.restart_units(i) if not live.is_live(u)]
+        if joiners:
+            delay = max(inj.restart_delay(u, attempts.get(u, 0)) or 0.0
+                        for u in joiners)
+            for u in joiners:
+                attempts[u] = attempts.get(u, 0) + 1
+            with jax.profiler.TraceAnnotation("repro.rejoin"):
                 state, p, step, info = _rejoin(
                     model, optimizer, sync, state, int(p), joiners, live,
                     axis_name=axis_name, microbatch=microbatch)
-                sync = info.pop("sync")
-                netp = net or cost_model.testbed()
-                state_nbytes = info.get("state_nbytes", 0.0)
-                entry = {"step": i, "event": "join", **info,
-                         "join_reshard_bytes":
-                             cost_model.join_reshard_bytes(
-                                 state_nbytes, info["p_old"]),
-                         "recovery_time": cost_model.recovery_time(
-                             0.0, delay, info["p_old"], info["p_new"],
-                             netp, state_nbytes=state_nbytes)}
-                history.append(entry)
-                if callback:
-                    callback(entry)
-            dead = [u for u in live.live
-                    if inj.is_killed(u, i, attempts.get(u, 0))]
-            if dead:
-                if len(dead) >= live.live_count:
-                    raise ValueError(
-                        f"fault schedule kills every live device at "
-                        f"step {i} — no survivor group to reconfigure to")
-                state, p, step, info = _reconfigure(
-                    model, optimizer, sync, state, int(p), dead, live,
-                    axis_name=axis_name, microbatch=microbatch)
-                sync = info.pop("sync")
-                netp = net or cost_model.testbed()
-                entry = {"step": i, "event": "reconfigure",
-                         "killed": dead, **info,
-                         "reconfig_time": cost_model.reconfig_time(
-                             info.get("state_nbytes", 0.0), info["p_old"],
-                             info["p_new"], netp,
-                             survivors=len(info["survivors"]))}
-                history.append(entry)
-                if callback:
-                    callback(entry)
-        shard = shard_batch(batch, p)
-        if inj is not None:
-            for r, u in enumerate(live.live):
-                if inj.active(u, i):
-                    noisy = inj.corrupt(
-                        jax.tree.map(lambda l: l[r], shard), u, i)
-                    shard = jax.tree.map(
-                        lambda l, x: l.at[r].set(x), shard, noisy)
-        state, metrics = step(state, shard)
-        if i % log_every == 0:
-            entry = {k: float(v) for k, v in metrics.items()}
-            entry["step"] = i
+            sync = info.pop("sync")
+            netp = net or cost_model.testbed()
+            state_nbytes = info.get("state_nbytes", 0.0)
+            entry = {"step": i, "event": "join", **info,
+                     "join_reshard_bytes":
+                         cost_model.join_reshard_bytes(
+                             state_nbytes, info["p_old"]),
+                     "recovery_time": cost_model.recovery_time(
+                         0.0, delay, info["p_old"], info["p_new"],
+                         netp, state_nbytes=state_nbytes)}
             history.append(entry)
             if callback:
                 callback(entry)
+        dead = [u for u in live.live
+                if inj.is_killed(u, i, attempts.get(u, 0))]
+        if dead:
+            if len(dead) >= live.live_count:
+                raise ValueError(
+                    f"fault schedule kills every live device at "
+                    f"step {i} — no survivor group to reconfigure to")
+            with jax.profiler.TraceAnnotation("repro.reconfigure"):
+                state, p, step, info = _reconfigure(
+                    model, optimizer, sync, state, int(p), dead, live,
+                    axis_name=axis_name, microbatch=microbatch)
+            sync = info.pop("sync")
+            netp = net or cost_model.testbed()
+            entry = {"step": i, "event": "reconfigure",
+                     "killed": dead, **info,
+                     "reconfig_time": cost_model.reconfig_time(
+                         info.get("state_nbytes", 0.0), info["p_old"],
+                         info["p_new"], netp,
+                         survivors=len(info["survivors"]))}
+            history.append(entry)
+            if callback:
+                callback(entry)
+
+    for i, batch in enumerate(batches):
+        with jax.profiler.StepTraceAnnotation("train", step_num=i):
+            if inj is not None:
+                membership_events(i)
+            shard = shard_batch(batch, p)
+            if inj is not None:
+                for r, u in enumerate(live.live):
+                    if inj.active(u, i):
+                        noisy = inj.corrupt(
+                            jax.tree.map(lambda l: l[r], shard), u, i)
+                        shard = jax.tree.map(
+                            lambda l, x: l.at[r].set(x), shard, noisy)
+            state, metrics = step(state, shard)
+            if i % log_every == 0:
+                with jax.profiler.TraceAnnotation("repro.metrics_fetch"):
+                    entry = {k: float(v) for k, v in metrics.items()}
+                entry["step"] = i
+                history.append(entry)
+                if callback:
+                    callback(entry)
     return state, history
 
 

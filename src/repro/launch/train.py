@@ -162,9 +162,12 @@ def make_overlap_grad_fn(model: Model, stages, schedule,
                 gp, ct = vjps[s](ct)
             else:
                 (gp,) = vjps[0](ct)
-            shards[s] = comm.reduce_scatter_bucket(
-                schedule.pack_bucket(s, gp), schedule, s)
-        g_shard = shards[0] if S == 1 else jnp.concatenate(shards)
+            with jax.named_scope("sync.pack"):
+                seg = schedule.pack_bucket(s, gp)
+            with jax.named_scope("sync.reduce_scatter"):
+                shards[s] = comm.reduce_scatter_bucket(seg, schedule, s)
+        with jax.named_scope("sync.reduce_scatter"):
+            g_shard = shards[0] if S == 1 else jnp.concatenate(shards)
         return loss, metrics, g_shard
 
     return grad_fn
@@ -461,18 +464,21 @@ def train_loop(model: Model, optimizer: Optimizer, sync: SyncConfig,
     for i, batch in enumerate(batches):
         if i < start:
             continue            # covered by the restored checkpoint
-        state, metrics = step_fn(state, batch)
-        if i % log_every == 0:
-            entry = {k: float(v) for k, v in metrics.items()}
-            entry["step"] = i
-            history.append(entry)
-            if callback:
-                callback(entry)
-        if (checkpoint_every and checkpoint_dir
-                and (i + 1) % checkpoint_every == 0):
-            ckpt.save_checkpoint(
-                ckpt.checkpoint_path(checkpoint_dir, i + 1), state,
-                step=i + 1)
+        with jax.profiler.StepTraceAnnotation("train", step_num=i):
+            state, metrics = step_fn(state, batch)
+            if i % log_every == 0:
+                with jax.profiler.TraceAnnotation("repro.metrics_fetch"):
+                    entry = {k: float(v) for k, v in metrics.items()}
+                entry["step"] = i
+                history.append(entry)
+                if callback:
+                    callback(entry)
+            if (checkpoint_every and checkpoint_dir
+                    and (i + 1) % checkpoint_every == 0):
+                with jax.profiler.TraceAnnotation("repro.checkpoint_save"):
+                    ckpt.save_checkpoint(
+                        ckpt.checkpoint_path(checkpoint_dir, i + 1), state,
+                        step=i + 1)
     return state, history
 
 

@@ -187,33 +187,30 @@ def _build_decoder(cfg: ModelConfig, dtype) -> Model:
         p["layers"] = init_stack(k2, cfg, dtype)
         return p
 
-    def backbone(p, x, prefix_len=0):
-        x, aux = apply_stack(p["layers"], x, cfg, prefix_len=prefix_len)
-        return rms_norm(x, p["final_norm"], cfg.norm_eps), aux
+    def embed(p, batch):
+        with jax.named_scope("model.embed"):
+            x = _embed(p, batch["tokens"], cfg)
+            if n_img:
+                x = jnp.concatenate(
+                    [batch["image_embeds"].astype(x.dtype), x], axis=1
+                )
+            return x
 
     def forward(p, batch):
-        x = _embed(p, batch["tokens"], cfg)
-        prefix = 0
-        if n_img:
-            x = jnp.concatenate(
-                [batch["image_embeds"].astype(x.dtype), x], axis=1
-            )
-            prefix = n_img
-        h, _ = backbone(p, x, prefix)
-        return _logits(p, h, cfg)
+        x, _ = apply_stack(p["layers"], embed(p, batch), cfg,
+                           prefix_len=n_img)
+        with jax.named_scope("model.head_loss"):
+            h = rms_norm(x, p["final_norm"], cfg.norm_eps)
+            return _logits(p, h, cfg)
 
     def loss_fn(p, batch):
-        x = _embed(p, batch["tokens"], cfg)
-        prefix = 0
-        if n_img:
-            x = jnp.concatenate(
-                [batch["image_embeds"].astype(x.dtype), x], axis=1
-            )
-            prefix = n_img
-        h, aux = backbone(p, x, prefix)
-        if n_img:
-            h = h[:, n_img:]
-        xent = _sequence_xent(p, h, batch["labels"], cfg)
+        x, aux = apply_stack(p["layers"], embed(p, batch), cfg,
+                             prefix_len=n_img)
+        with jax.named_scope("model.head_loss"):
+            h = rms_norm(x, p["final_norm"], cfg.norm_eps)
+            if n_img:
+                h = h[:, n_img:]
+            xent = _sequence_xent(p, h, batch["labels"], cfg)
         loss = xent + aux
         return loss, {"xent": xent, "aux": aux}
 
@@ -279,10 +276,11 @@ def _decoder_overlap_stages(cfg: ModelConfig, loss_fn) -> Callable:
             return p
 
         def embed_fn(p0, batch):
-            x = _embed(p0, batch["tokens"], cfg)
-            if n_img:
-                x = jnp.concatenate(
-                    [batch["image_embeds"].astype(x.dtype), x], axis=1)
+            with jax.named_scope("model.embed"):
+                x = _embed(p0, batch["tokens"], cfg)
+                if n_img:
+                    x = jnp.concatenate(
+                        [batch["image_embeds"].astype(x.dtype), x], axis=1)
             carry = {"x": x, "aux": jnp.zeros((), jnp.float32)}
             if cfg.tie_embeddings:
                 carry["emb"] = p0["embedding"]
@@ -296,12 +294,13 @@ def _decoder_overlap_stages(cfg: ModelConfig, loss_fn) -> Callable:
             return out
 
         def head_fn(ph, carry, batch):
-            h = rms_norm(carry["x"], ph["final_norm"], cfg.norm_eps)
-            if n_img:
-                h = h[:, n_img:]
-            pl = ({"embedding": carry["emb"]} if cfg.tie_embeddings
-                  else {"lm_head": ph["lm_head"]})
-            xent = _sequence_xent(pl, h, batch["labels"], cfg)
+            with jax.named_scope("model.head_loss"):
+                h = rms_norm(carry["x"], ph["final_norm"], cfg.norm_eps)
+                if n_img:
+                    h = h[:, n_img:]
+                pl = ({"embedding": carry["emb"]} if cfg.tie_embeddings
+                      else {"lm_head": ph["lm_head"]})
+                xent = _sequence_xent(pl, h, batch["labels"], cfg)
             return xent + carry["aux"], {"xent": xent, "aux": carry["aux"]}
 
         fns = (embed_fn,) + (layer_fn,) * k + (head_fn,)
@@ -357,15 +356,25 @@ def _build_ssm(cfg: ModelConfig, dtype) -> Model:
             return h + y, None
 
         body_fn = jax.checkpoint(body) if cfg.remat else body
-        x, _ = layer_scan(body_fn, x, p["layers"], cfg)
-        return rms_norm(x, p["final_norm"], cfg.norm_eps)
+        with jax.named_scope("model.layers"):
+            x, _ = layer_scan(body_fn, x, p["layers"], cfg)
+        return x
+
+    def embed(p, batch):
+        with jax.named_scope("model.embed"):
+            return _embed(p, batch["tokens"], cfg)
 
     def forward(p, batch):
-        return _logits(p, backbone(p, _embed(p, batch["tokens"], cfg)), cfg)
+        x = backbone(p, embed(p, batch))
+        with jax.named_scope("model.head_loss"):
+            return _logits(p, rms_norm(x, p["final_norm"], cfg.norm_eps),
+                           cfg)
 
     def loss_fn(p, batch):
-        h = backbone(p, _embed(p, batch["tokens"], cfg))
-        loss = _sequence_xent(p, h, batch["labels"], cfg)
+        x = backbone(p, embed(p, batch))
+        with jax.named_scope("model.head_loss"):
+            h = rms_norm(x, p["final_norm"], cfg.norm_eps)
+            loss = _sequence_xent(p, h, batch["labels"], cfg)
         return loss, {"xent": loss}
 
     def init_cache(batch, max_seq):
@@ -415,16 +424,24 @@ def _build_hybrid(cfg: ModelConfig, dtype) -> Model:
         p.update(init_hybrid(k2, cfg, dtype))
         return p
 
+    def backbone(p, batch):
+        with jax.named_scope("model.embed"):
+            x = _embed(p, batch["tokens"], cfg)
+        with jax.named_scope("model.layers"):
+            x, _ = apply_hybrid(p, x, cfg)
+        return x
+
     def forward(p, batch):
-        x = _embed(p, batch["tokens"], cfg)
-        x, _ = apply_hybrid(p, x, cfg)
-        return _logits(p, rms_norm(x, p["final_norm"], cfg.norm_eps), cfg)
+        x = backbone(p, batch)
+        with jax.named_scope("model.head_loss"):
+            return _logits(p, rms_norm(x, p["final_norm"], cfg.norm_eps),
+                           cfg)
 
     def loss_fn(p, batch):
-        x = _embed(p, batch["tokens"], cfg)
-        x, _ = apply_hybrid(p, x, cfg)
-        h = rms_norm(x, p["final_norm"], cfg.norm_eps)
-        loss = _sequence_xent(p, h, batch["labels"], cfg)
+        x = backbone(p, batch)
+        with jax.named_scope("model.head_loss"):
+            h = rms_norm(x, p["final_norm"], cfg.norm_eps)
+            loss = _sequence_xent(p, h, batch["labels"], cfg)
         return loss, {"xent": loss}
 
     def init_cache(batch, max_seq):
@@ -471,34 +488,43 @@ def _build_enc_dec(cfg: ModelConfig, dtype) -> Model:
         return p
 
     def encode(p, frames):
-        x = frames.astype(dtype) + p["enc_pos"]["pos_embedding"][: frames.shape[1]]
+        with jax.named_scope("model.embed"):
+            x = (frames.astype(dtype)
+                 + p["enc_pos"]["pos_embedding"][: frames.shape[1]])
 
         def body(h, lp):
             return apply_enc_layer(lp, h, cfg), None
 
         body_fn = jax.checkpoint(body) if cfg.remat else body
-        x, _ = layer_scan(body_fn, x, p["encoder"], cfg)
-        return layer_norm(x, p["enc_final_norm"], p["enc_final_norm_b"])
+        with jax.named_scope("model.layers"):
+            x, _ = layer_scan(body_fn, x, p["encoder"], cfg)
+            return layer_norm(x, p["enc_final_norm"], p["enc_final_norm_b"])
 
     def decode_full(p, enc, tokens):
-        x = p["embedding"][tokens]
-        x = x + p["dec_pos"]["pos_embedding"][: tokens.shape[1]]
+        with jax.named_scope("model.embed"):
+            x = p["embedding"][tokens]
+            x = x + p["dec_pos"]["pos_embedding"][: tokens.shape[1]]
 
         def body(h, lp):
             return apply_dec_layer(lp, h, enc, cfg), None
 
         body_fn = jax.checkpoint(body) if cfg.remat else body
-        x, _ = layer_scan(body_fn, x, p["decoder"], cfg)
-        return layer_norm(x, p["final_norm"], p["final_norm_b"])
+        with jax.named_scope("model.layers"):
+            x, _ = layer_scan(body_fn, x, p["decoder"], cfg)
+        with jax.named_scope("model.head_loss"):
+            return layer_norm(x, p["final_norm"], p["final_norm_b"])
 
     def forward(p, batch):
         enc = encode(p, batch["audio_frames"])
-        return _logits(p, decode_full(p, enc, batch["tokens"]), cfg)
+        h = decode_full(p, enc, batch["tokens"])
+        with jax.named_scope("model.head_loss"):
+            return _logits(p, h, cfg)
 
     def loss_fn(p, batch):
         enc = encode(p, batch["audio_frames"])
         h = decode_full(p, enc, batch["tokens"])
-        loss = _sequence_xent(p, h, batch["labels"], cfg)
+        with jax.named_scope("model.head_loss"):
+            loss = _sequence_xent(p, h, batch["labels"], cfg)
         return loss, {"xent": loss}
 
     def init_cache(batch, max_seq):

@@ -64,19 +64,21 @@ def init_block(key, cfg: ModelConfig, dtype) -> dict:
 def apply_block(params: dict, x: jax.Array, cfg: ModelConfig, *,
                 prefix_len: int = 0) -> tuple[jax.Array, jax.Array]:
     spec = attn_spec(cfg, prefix_len=prefix_len)
-    h = rms_norm(x, params["attn_norm"], cfg.norm_eps)
-    x = x + multi_head_attention(params["attn"], h, spec)
-    h = rms_norm(x, params["ffn_norm"], cfg.norm_eps)
-    if cfg.arch_type == "moe":
-        y, aux = moe_block(
-            params["moe"], h, num_experts=cfg.num_experts, top_k=cfg.top_k,
-            capacity_factor=cfg.capacity_factor,
-            aux_weight=cfg.router_aux_weight,
-        )
-    else:
-        mlp = gelu_ffn if cfg.arch_type == "vlm" else ffn
-        y, aux = mlp(params["mlp"], h), jnp.zeros((), jnp.float32)
-    return x + y, aux
+    with jax.named_scope("model.attention"):
+        h = rms_norm(x, params["attn_norm"], cfg.norm_eps)
+        x = x + multi_head_attention(params["attn"], h, spec)
+    with jax.named_scope("model.mlp"):
+        h = rms_norm(x, params["ffn_norm"], cfg.norm_eps)
+        if cfg.arch_type == "moe":
+            y, aux = moe_block(
+                params["moe"], h, num_experts=cfg.num_experts,
+                top_k=cfg.top_k, capacity_factor=cfg.capacity_factor,
+                aux_weight=cfg.router_aux_weight,
+            )
+        else:
+            mlp = gelu_ffn if cfg.arch_type == "vlm" else ffn
+            y, aux = mlp(params["mlp"], h), jnp.zeros((), jnp.float32)
+        return x + y, aux
 
 
 def decode_block(params: dict, x: jax.Array, cache: dict, cfg: ModelConfig):
@@ -139,7 +141,9 @@ def apply_stack(stacked: dict, x: jax.Array, cfg: ModelConfig, *,
         return (h, aux + a), None
 
     body_fn = jax.checkpoint(body) if cfg.remat else body
-    (x, aux), _ = layer_scan(body_fn, (x, jnp.zeros((), jnp.float32)), stacked, cfg)
+    with jax.named_scope("model.layers"):
+        (x, aux), _ = layer_scan(body_fn, (x, jnp.zeros((), jnp.float32)),
+                                 stacked, cfg)
     return x, aux
 
 

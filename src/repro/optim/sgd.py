@@ -327,30 +327,34 @@ def scatter_update_gather(spec: flatbuf.FlatBuffer, grads: Any, params: Any,
     nr = comm.rings_for(spec.nbytes)
     _, total = flatbuf.shard_geometry(spec.size, p, nr)
 
-    gbuf = flatbuf.pack_padded(spec, grads, total)
-    pbuf = flatbuf.pack_padded(spec, params, total)
+    with jax.named_scope("sync.pack"):
+        gbuf = flatbuf.pack_padded(spec, grads, total)
+        pbuf = flatbuf.pack_padded(spec, params, total)
 
     if p == 1:
         g_shard, p_shard = gbuf, pbuf
     else:
-        g_shard = comm.reduce_scatter(gbuf, num_rings=nr)
-        p_shard = comm.shard_select(pbuf, num_rings=nr)
-    if mean:
-        g_shard = g_shard / p
-    wd = hyper.get("weight_decay", 0.0) or 0.0
-    if name == "sgd" and wd:
-        # coupled L2, matching per-leaf optim.sgd; adamw decays decoupled
-        # inside its kernel
-        g_shard = g_shard + wd * p_shard
-
-    new_p_shard, new_state = _fused_shard_update(
-        name, hyper, p_shard, opt_state, g_shard, interpret)
+        with jax.named_scope("sync.reduce_scatter"):
+            g_shard = comm.reduce_scatter(gbuf, num_rings=nr)
+            p_shard = comm.shard_select(pbuf, num_rings=nr)
+    with jax.named_scope("sync.update"):
+        if mean:
+            g_shard = g_shard / p
+        wd = hyper.get("weight_decay", 0.0) or 0.0
+        if name == "sgd" and wd:
+            # coupled L2, matching per-leaf optim.sgd; adamw decays
+            # decoupled inside its kernel
+            g_shard = g_shard + wd * p_shard
+        new_p_shard, new_state = _fused_shard_update(
+            name, hyper, p_shard, opt_state, g_shard, interpret)
 
     if p == 1:
         new_pbuf = new_p_shard
     else:
-        new_pbuf = comm.allgather(new_p_shard, num_rings=nr)
-    return spec.unpack(new_pbuf[:spec.size]), new_state
+        with jax.named_scope("sync.allgather"):
+            new_pbuf = comm.allgather(new_p_shard, num_rings=nr)
+    with jax.named_scope("sync.unpack"):
+        return spec.unpack(new_pbuf[:spec.size]), new_state
 
 
 def optstate_sched_init(hyper, schedule, state_dtypes=None) -> Any:
@@ -421,19 +425,23 @@ def overlap_update(schedule, g_shard: jax.Array, staged_params: Any,
             f"the gradient group's size (bucket_schedule(spec, counts, "
             f"p={p}))")
 
-    pbuf = schedule.spec.pack(staged_params)
-    p_shard = comm.shard_select_sched(pbuf, schedule)
-    if mean:
-        g_shard = g_shard / p
-    wd = hyper.get("weight_decay", 0.0) or 0.0
-    if name == "sgd" and wd:
-        g_shard = g_shard + wd * p_shard
+    with jax.named_scope("sync.pack"):
+        pbuf = schedule.spec.pack(staged_params)
+    with jax.named_scope("sync.reduce_scatter"):
+        p_shard = comm.shard_select_sched(pbuf, schedule)
+    with jax.named_scope("sync.update"):
+        if mean:
+            g_shard = g_shard / p
+        wd = hyper.get("weight_decay", 0.0) or 0.0
+        if name == "sgd" and wd:
+            g_shard = g_shard + wd * p_shard
+        new_p_shard, new_state = _fused_shard_update(
+            name, hyper, p_shard, opt_state, g_shard, interpret)
 
-    new_p_shard, new_state = _fused_shard_update(
-        name, hyper, p_shard, opt_state, g_shard, interpret)
-
-    new_pbuf = comm.allgather_sched(new_p_shard, schedule)
-    return schedule.spec.unpack(new_pbuf), new_state
+    with jax.named_scope("sync.allgather"):
+        new_pbuf = comm.allgather_sched(new_p_shard, schedule)
+    with jax.named_scope("sync.unpack"):
+        return schedule.spec.unpack(new_pbuf), new_state
 
 
 def _flat_optimizer(hyper: dict, spec: flatbuf.FlatBuffer,
