@@ -6,6 +6,10 @@ chunk attends to a *statically truncated* KV range (triangular skipping —
 no FLOPs spent on fully-masked blocks), and scans over KV blocks inside a
 chunk with a running (max, sum, acc) — flash-attention structure in pure
 jnp, which both bounds memory and lowers on any backend.
+
+On a TPU, plain causal self-attention (no prefix, no window, equal
+lengths a multiple of the kernel's block) takes the Pallas kernel of
+``kernels/flash_attention`` instead: its score blocks stay in VMEM.
 """
 from __future__ import annotations
 
@@ -16,6 +20,9 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import common
+from repro.kernels.flash_attention.flash_attention import pick_blocks
+from repro.kernels.flash_attention.ops import causal_attention
 from repro.models.layers import apply_rope, dense_init, rms_norm
 
 NEG_INF = -1e30
@@ -136,6 +143,29 @@ def _chunk_attend(q, k, v, qpos0: int, spec: AttnSpec, kv_chunk: int,
     return jnp.moveaxis(out, 3, 1).astype(q.dtype)  # (B, qc, KV, G, D)
 
 
+def _partitioned() -> bool:
+    """Whether the ambient mesh has an axis that XLA partitions (GSPMD):
+    a Mosaic kernel cannot be partitioned automatically, only inside
+    shard_map, whose axes are manual."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return any(mesh.shape[a] > 1
+               for a in mesh.auto_axes + mesh.explicit_axes)
+
+
+def takes_flash_kernel(spec: AttnSpec, q_len: int, kv_len: int, *,
+                       cross: bool = False) -> bool:
+    """Whether ``multi_head_attention`` runs the causal flash kernel: on a
+    TPU outside a GSPMD-partitioned mesh, for causal self-attention with
+    no prefix and no window, over equal lengths that the kernel's block
+    divides."""
+    return (not common.use_interpret() and not _partitioned()
+            and not cross and spec.causal
+            and spec.prefix_len == 0 and spec.sliding_window == 0
+            and q_len == kv_len
+            and pick_blocks(q_len, spec.num_heads // spec.num_kv_heads)
+            is not None)
+
+
 def multi_head_attention(
     params: dict,
     x: jax.Array,
@@ -156,6 +186,9 @@ def multi_head_attention(
     q, k, v = _project_qkv(params, x, x_kv, spec, positions, kv_positions)
     KV, G = spec.num_kv_heads, spec.num_heads // spec.num_kv_heads
     q = q.reshape(B, Sq, KV, G, spec.head_dim)
+    if takes_flash_kernel(spec, Sq, Sk, cross=x_kv is not x):
+        out = causal_attention(q, k, v).reshape(B, Sq, -1)
+        return jnp.einsum("bsh,hd->bsd", out, params["wo"])
 
     nq = max(1, math.ceil(Sq / q_chunk))
     outs = []
@@ -252,12 +285,18 @@ def reference_attention(params, x, spec: AttnSpec, x_kv=None) -> jax.Array:
         jnp.arange(Sq)[None, :], jnp.arange(Sk)[None, :],
     )
     KV, G, D = spec.num_kv_heads, spec.num_heads // spec.num_kv_heads, spec.head_dim
-    q = q.reshape(B, Sq, KV, G, D)
+    out = reference_attend(q.reshape(B, Sq, KV, G, D), k, v, spec)
+    return jnp.einsum(
+        "bsh,hd->bsd", out.reshape(B, Sq, spec.num_heads * D), params["wo"]
+    )
+
+
+def reference_attend(q, k, v, spec: AttnSpec) -> jax.Array:
+    """The oracle's core, the whole score square: q (B, Sq, KV, G, D),
+    k/v (B, Sk, KV, D) -> (B, Sq, KV, G, D)."""
+    Sq, Sk, D = q.shape[1], k.shape[1], q.shape[-1]
     s = jnp.einsum("bqhgd,bkhd->bhgqk", q, k).astype(jnp.float32) / math.sqrt(D)
     mask = _block_mask(jnp.arange(Sq), jnp.arange(Sk), spec)
     s = jnp.where(mask[None, None, None], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
-    out = jnp.einsum("bhgqk,bkhd->bqhgd", p.astype(x.dtype), v)
-    return jnp.einsum(
-        "bsh,hd->bsd", out.reshape(B, Sq, spec.num_heads * D), params["wo"]
-    )
+    return jnp.einsum("bhgqk,bkhd->bqhgd", p.astype(v.dtype), v)

@@ -11,7 +11,9 @@ The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and every xdist worker
 imports this file.
 """
+import base64
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -22,13 +24,15 @@ from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import get_config
 from repro.core.hierarchy import SyncConfig
+from repro.kernels.flash_attention.ops import causal_attention
 from repro.kernels.fused_elastic import fused_elastic as fe
 from repro.kernels.fused_optim.fused_optim import adagrad_flat, adamw_flat
 from repro.kernels.fused_sgd.fused_sgd import sgd_momentum_flat
 from repro.kernels.quant_bucket import quant_bucket as qb
 from repro.kernels.tensor_reduce.tensor_reduce import group_reduce_flat
 from repro.launch import shard_driver as SD
-from repro.launch.train import grad_spec, make_train_state, make_train_step
+from repro.launch.train import (grad_spec, make_train_state, make_train_step,
+                                state_specs)
 from repro.models.model import build_model
 from repro.optim.sgd import sgd
 
@@ -149,6 +153,42 @@ def test_kernel_compiles_for_v5e_at_qwen2_flat_size(name, one_chip, qwen):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def _kernel_bodies(text: str) -> list[bytes]:
+    """The decoded body of every Pallas kernel in compiled HLO text."""
+    return [base64.b64decode(b) for b in
+            re.findall(r'"body":"([A-Za-z0-9+/=]+)"', text)]
+
+
+ATTENTION_MARK = b"gqa_causal_"     # every attention kernel's name
+
+
+@pytest.mark.parametrize("heads, kv_heads, head_dim", [
+    (14, 2, 64),        # qwen2-0.5b
+    (16, 2, 128),       # qwen2.5-3b
+])
+def test_attention_kernel_compiles_for_v5e_at_the_cells_shapes(
+        heads, kv_heads, head_dim, one_chip, monkeypatch):
+    """The causal flash kernel's forward and backward at 8 x 1024 tokens:
+    three kernels, each named for attention, none of them the update's."""
+    import repro.kernels.common as kc
+
+    monkeypatch.setattr(kc, "use_interpret", lambda: False)
+    G = heads // kv_heads
+    q = jax.ShapeDtypeStruct((8, 1024, kv_heads, G, head_dim), BF16,
+                             sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((8, 1024, kv_heads, head_dim), BF16,
+                              sharding=one_chip)
+
+    def fwd_bwd(q, k, v, do):
+        o, vjp = jax.vjp(causal_attention, q, k, v)
+        return o, vjp(do)
+
+    bodies = _kernel_bodies(_compile(fwd_bwd, (q, kv, kv, q)).as_text())
+    assert len(bodies) == 3
+    assert all(ATTENTION_MARK in b for b in bodies)
+    assert not any(b"sgd_momentum_flat" in b for b in bodies)
+
+
 def test_full_width_train_step_compiles_for_one_v5e(one_chip, qwen,
                                                     monkeypatch):
     """qwen2-0.5b at published width, fused flat mpi_sgd with momentum,
@@ -167,9 +207,85 @@ def test_full_width_train_step_compiles_for_one_v5e(one_chip, qwen,
     tok = jax.ShapeDtypeStruct((8, 1024), jnp.int32, sharding=one_chip)
     compiled = _compile(make_train_step(model, opt, sync, None),
                         (state, {"tokens": tok, "labels": tok}), (0,))
-    assert "tpu_custom_call" in compiled.as_text()
+    bodies = _kernel_bodies(compiled.as_text())
+    # the update; attention's forward, its recomputation in the layer
+    # scan's backward, and its dK/dV and dQ kernels
+    assert sum(b"sgd_momentum_flat" in b for b in bodies) == 1
+    assert sum(ATTENTION_MARK in b for b in bodies) == 4
+    assert not any(b"sgd_momentum_flat" in b and ATTENTION_MARK in b
+                   for b in bodies)
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < V5E_HBM
+
+
+def test_gspmd_step_compiles_for_four_v5e(topo, monkeypatch):
+    """The GSPMD step (a mesh given to make_train_step, traced under it)
+    on a described 2x2 data axis: XLA cannot partition a Mosaic kernel,
+    so its attention keeps the jnp path and the step compiles. Two
+    layers of qwen2-0.5b, batch 8 x seq 1024."""
+    import dataclasses
+
+    import repro.kernels.common as kc
+
+    monkeypatch.setattr(kc, "use_interpret", lambda: False)
+    model = build_model(dataclasses.replace(get_config("qwen2-0.5b"),
+                                            num_layers=2))
+    opt = sgd(0.01, momentum=0.9)
+    sync = SyncConfig(mode="mpi_sgd", num_clients=1)
+    mesh = Mesh(np.array(topo.devices).reshape(4, 1), ("data", "model"))
+    abstract = make_train_state(model, opt, sync, abstract=True, mesh=mesh)
+    state = jax.tree.map(
+        lambda spec, s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=NamedSharding(mesh, spec)),
+        state_specs(abstract, mesh, sync), abstract,
+        is_leaf=lambda x: isinstance(x, P))
+    tok = jax.ShapeDtypeStruct((8, 1024), jnp.int32,
+                               sharding=NamedSharding(mesh, P("data")))
+    with jax.set_mesh(mesh):
+        compiled = _compile(make_train_step(model, opt, sync, mesh),
+                            (state, {"tokens": tok, "labels": tok}))
+    assert not any(ATTENTION_MARK in b
+                   for b in _kernel_bodies(compiled.as_text()))
+
+
+@pytest.mark.parametrize("mode", ["mpi_sgd", "mpi_esgd"])
+def test_gspmd_step_without_an_ambient_mesh_compiles_for_four_v5e(
+        mode, topo, monkeypatch):
+    """The GSPMD step jitted with no ambient mesh, as chip_smoke.py's
+    mpi_esgd 2x2 reference and ``train_loop(..., mesh)`` jit it:
+    make_train_step traces it under its mesh, so attention keeps the jnp
+    path and the step compiles. Two layers of qwen2-0.5b, batch 8 x seq
+    1024 (mpi_esgd: 2 clients of 4, over pod x data)."""
+    import dataclasses
+
+    import repro.kernels.common as kc
+
+    monkeypatch.setattr(kc, "use_interpret", lambda: False)
+    model = build_model(dataclasses.replace(get_config("qwen2-0.5b"),
+                                            num_layers=2))
+    opt = sgd(0.01, momentum=0.9)
+    if mode == "mpi_sgd":
+        sync = SyncConfig(mode="mpi_sgd", num_clients=1)
+        mesh = Mesh(np.array(topo.devices).reshape(4, 1), ("data", "model"))
+        tok = (8, 1024), P("data")
+    else:
+        sync = SyncConfig(mode="mpi_esgd", num_clients=2, esgd_interval=2)
+        mesh = Mesh(np.array(topo.devices).reshape(2, 2),
+                    (SD.POD_AXIS, SD.DATA_AXIS))
+        tok = (2, 4, 1024), P(SD.POD_AXIS, SD.DATA_AXIS)
+    abstract = make_train_state(model, opt, sync, abstract=True, mesh=mesh)
+    state = jax.tree.map(
+        lambda spec, s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=NamedSharding(mesh, spec)),
+        state_specs(abstract, mesh, sync), abstract,
+        is_leaf=lambda x: isinstance(x, P))
+    tok = jax.ShapeDtypeStruct(tok[0], jnp.int32,
+                               sharding=NamedSharding(mesh, tok[1]))
+    assert jax.sharding.get_abstract_mesh().empty
+    compiled = _compile(make_train_step(model, opt, sync, mesh),
+                        (state, {"tokens": tok, "labels": tok}), (0,))
+    assert not any(ATTENTION_MARK in b
+                   for b in _kernel_bodies(compiled.as_text()))
 
 
 @pytest.mark.parametrize("mode,shape", [
